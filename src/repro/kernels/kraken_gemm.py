@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_ACTIVATIONS = {
+ACTIVATIONS = {
     None: lambda x: x,
     "relu": lambda x: jnp.maximum(x, 0.0),
     "silu": lambda x: x * jax.nn.sigmoid(x),
@@ -40,7 +40,7 @@ _ACTIVATIONS = {
 def _epilogue(acc, bias_ref, activation):
     if bias_ref is not None:
         acc = acc + bias_ref[...].astype(jnp.float32)
-    return _ACTIVATIONS[activation](acc)
+    return ACTIVATIONS[activation](acc)
 
 
 def _ws_kernel(a_ref, b_ref, *rest, activation: Optional[str], has_bias: bool):

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.core import elastic
 from repro.kernels import ref
-from repro.kernels.kraken_gemm import kraken_gemm
+from repro.kernels.kraken_gemm import ACTIVATIONS, kraken_gemm
 from repro.kernels.swa_attention import swa_attention as _swa_pallas
 
 
@@ -45,6 +45,8 @@ def kraken_matmul(a: jnp.ndarray, b: jnp.ndarray, *,
 
     The single compute primitive of the framework — conv, FC, attention
     projections and MoE experts all route through here (DESIGN.md §2).
+    It is differentiable: the backward pass is two more GEMMs through the
+    same kernel (``dA = dZ @ B^T``, ``dB = A^T @ dZ``).
 
     ``tile_mode`` selects the tile plan source (``"model"`` | ``"cached"`` |
     ``"autotune"``; ``None`` defers to the process-wide ``repro.tuning``
@@ -56,6 +58,12 @@ def kraken_matmul(a: jnp.ndarray, b: jnp.ndarray, *,
     if not use_pallas and not interpret:
         return ref.matmul(a, b, bias=bias, activation=activation,
                           out_dtype=out_dtype)
+    return _matmul_vjp(a, b, bias, activation, out_dtype or a.dtype,
+                       bool(interpret), tile_mode)
+
+
+def _gemm(a, b, bias, activation, out_dtype, interpret, tile_mode):
+    """One padded ``kraken_gemm`` call under the elastic tile plan."""
     m, k = a.shape
     _, n = b.shape
     cfg = elastic.choose_tiles(m, k, n, in_bytes=a.dtype.itemsize,
@@ -68,8 +76,39 @@ def kraken_matmul(a: jnp.ndarray, b: jnp.ndarray, *,
     out = kraken_gemm(
         ap, bp, bm=cfg.bm, bk=ap.shape[1] if cfg.schedule == "weight_stationary" else cfg.bk,
         bn=cfg.bn, schedule=cfg.schedule, bias=bias_p, activation=activation,
-        out_dtype=out_dtype or a.dtype, interpret=bool(interpret))
+        out_dtype=out_dtype, interpret=interpret)
     return out[:m, :n]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _matmul_vjp(a, b, bias, activation, out_dtype, interpret, tile_mode):
+    return _gemm(a, b, bias, activation, out_dtype, interpret, tile_mode)
+
+
+def _matmul_fwd(a, b, bias, activation, out_dtype, interpret, tile_mode):
+    out = _gemm(a, b, bias, activation, out_dtype, interpret, tile_mode)
+    return out, (a, b, bias)
+
+
+def _matmul_bwd(activation, out_dtype, interpret, tile_mode, res, g):
+    a, b, bias = res
+    if activation is None:
+        dz = g
+    else:
+        # the epilogue's derivative needs the pre-activation: recompute it
+        # (one more GEMM) rather than keep an f32 [M, N] residual alive
+        z = _gemm(a, b, bias, None, jnp.float32, interpret, tile_mode)
+        _, act_vjp = jax.vjp(ACTIVATIONS[activation], z)
+        dz = act_vjp(g.astype(jnp.float32))[0]
+    dz = dz.astype(a.dtype)
+    da = _gemm(dz, b.T, None, None, a.dtype, interpret, tile_mode)
+    db = _gemm(a.T, dz, None, None, b.dtype, interpret, tile_mode)
+    dbias = None if bias is None else \
+        jnp.sum(dz.astype(jnp.float32), axis=0).astype(bias.dtype)
+    return da, db, dbias
+
+
+_matmul_vjp.defvjp(_matmul_fwd, _matmul_bwd)
 
 
 def kraken_conv2d(x: jnp.ndarray, k: jnp.ndarray, *,

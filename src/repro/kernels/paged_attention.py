@@ -16,9 +16,14 @@ Layout per grid step ``(slot, kv_head, page_block)``:
 
   q        [1, 1, G, D]     resident across page blocks (output-stationary)
   k/v      ppb x [1, 1, ps, D]   physical pages, index-mapped via the table
-  pos      ppb x [1, ps]     absolute position per entry (-2^30 = empty)
-  k/v scale ppb x [1, 1, ps] f32 (int8 pools only; dequant fused in VMEM)
+  pos      ppb x [1, 1, ps]  absolute position per entry (-2^30 = empty)
+  k/v scale ppb x [1, 1, 1, ps] f32 (int8 pools only; folded into the dots)
   acc/m/l  VMEM scratch      online-softmax state, G x D
+
+Positions and scales get a singleton axis so each block's last two dims
+equal the array's (the TPU tiling rule for rows narrower than (8, 128)),
+and every per-entry vector stays a 2-D ``[1, n]`` lane row: the mask and
+the scales are built by concatenating rows along lanes, never 1-D vectors.
 
 ``pages_per_block`` (ppb) logical pages are fetched per step — the tunable
 the ``op_kind="paged_decode"`` autotuner measures.  Each page is its own
@@ -182,43 +187,49 @@ def _kernel(tbl_ref, qpos_ref, q_ref, *refs, ppb: int, nblk: int,
         # index-mapped to physical page 0: whatever was fetched, every one
         # of its entries must read as empty.
         live = (pid < n_pages) & ((pb * ppb + j) * page_size <= q_pos)
-        poss.append(jnp.where(live, pos_refs[j][0], POS_EMPTY))
-    kv_pos = jnp.concatenate(poss, axis=0)                # [ppb*ps]
+        poss.append(jnp.where(live, pos_refs[j][0], POS_EMPTY))   # [1, ps]
+    # every per-entry vector is a 2-D lane row: Mosaic concatenates
+    # [1, ps] tiles along lanes, not 1-D vectors
+    kv_pos = jnp.concatenate(poss, axis=1)                # [1, ppb*ps]
 
     # the block-skip predicate: does any entry survive the position mask?
     # Sentinel/unreached pages were forced to POS_EMPTY above, so this
     # subsumes the page-liveness test and additionally skips blocks whose
     # positions all fell out of the sliding window.  Everything beyond the
-    # cheap position vector — dequant, concat, both dots — stays inside
-    # the skipped body.
+    # cheap position row — dequant, concat, both dots — stays inside the
+    # skipped body.
     mask = (kv_pos >= 0) & (kv_pos <= q_pos)
     if window:
         mask = mask & (kv_pos > q_pos - window)
 
     @pl.when(jnp.any(mask))
     def _update():
-        ks, vs = [], []
-        for j in range(ppb):
-            kj = k_refs[j][0, 0]                          # [ps, D]
-            vj = v_refs[j][0, 0]
-            if quantized:
-                kj = kj.astype(jnp.float32) * ksc_refs[j][0, 0][:, None]
-                vj = vj.astype(jnp.float32) * vsc_refs[j][0, 0][:, None]
-            ks.append(kj)
-            vs.append(vj)
-        k = jnp.concatenate(ks, axis=0)                   # [ppb*ps, D]
-        v = jnp.concatenate(vs, axis=0)
+        k = jnp.concatenate([r[0, 0] for r in k_refs], axis=0)  # [ppb*ps, D]
+        v = jnp.concatenate([r[0, 0] for r in v_refs], axis=0)
         q = q_ref[0, 0]                                   # [G, D]
+        if quantized:
+            # dequant folded into the dots: a per-entry scale multiplies
+            # column s of q.k^T and row s of v, i.e. one lane row each
+            k_sc = jnp.concatenate([r[0, 0] for r in ksc_refs], axis=1)
+            v_sc = jnp.concatenate([r[0, 0] for r in vsc_refs], axis=1)
+            q = q.astype(jnp.float32)
+            k = k.astype(jnp.float32)
+            v = v.astype(jnp.float32)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [G, ppb*ps]
-        masked = jnp.where(mask[None, :], logits, -1e30)
+            preferred_element_type=jnp.float32)           # [G, ppb*ps]
+        if quantized:
+            logits = logits * k_sc
+        logits = logits * scale
+        masked = jnp.where(mask, logits, -1e30)
         m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
         m_cur = jnp.max(masked, axis=-1, keepdims=True)   # [G, 1]
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(masked - m_new)
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            p = p * v_sc
         acc_ref[...] = acc_prev * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -264,7 +275,9 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                       constant_values=n_pages)
     qpos_arr = jnp.broadcast_to(
         jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
-    pos_pages = jnp.asarray(pos_pages, jnp.int32)
+    # a singleton axis makes each per-page block's last two dims equal the
+    # array's, the TPU tiling rule for rows narrower than (8, 128)
+    pos_pages = jnp.asarray(pos_pages, jnp.int32).reshape(n_pages, 1, ps)
 
     def page_map(j, trail):
         def m(bi, hi, pb, tbl, qp):
@@ -277,8 +290,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         return m
 
     kv_trail = lambda hi: (hi, 0, 0)
-    pos_trail = lambda hi: (0,)
-    sc_trail = lambda hi: (hi, 0)
+    pos_trail = lambda hi: (0, 0)
+    sc_trail = lambda hi: (hi, 0, 0)
 
     in_specs = [pl.BlockSpec((1, 1, g, d),
                              lambda bi, hi, pb, tbl, qp: (bi, hi, 0, 0))]
@@ -286,16 +299,16 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                  for j in range(ppb)]
     in_specs += [pl.BlockSpec((1, 1, ps, d), page_map(j, kv_trail))
                  for j in range(ppb)]
-    in_specs += [pl.BlockSpec((1, ps), page_map(j, pos_trail))
+    in_specs += [pl.BlockSpec((1, 1, ps), page_map(j, pos_trail))
                  for j in range(ppb)]
     args = ([q.reshape(b, kvh, g, d)] + [k_pages] * ppb + [v_pages] * ppb
             + [pos_pages] * ppb)
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, ps), page_map(j, sc_trail))
-                     for j in range(ppb)]
-        in_specs += [pl.BlockSpec((1, 1, ps), page_map(j, sc_trail))
-                     for j in range(ppb)]
-        args += [k_scale] * ppb + [v_scale] * ppb
+        sc_spec = [pl.BlockSpec((1, 1, 1, ps), page_map(j, sc_trail))
+                   for j in range(ppb)]
+        in_specs += sc_spec + sc_spec
+        args += ([k_scale.reshape(n_pages, kvh, 1, ps)] * ppb
+                 + [v_scale.reshape(n_pages, kvh, 1, ps)] * ppb)
 
     from jax.experimental.pallas import tpu as pltpu
     grid_spec = pltpu.PrefetchScalarGridSpec(

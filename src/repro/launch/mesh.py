@@ -10,6 +10,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,7 +22,8 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {need} devices, have {len(devices)} "
             f"(dry-run sets --xla_force_host_platform_device_count=512)")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices[:need])
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -31,4 +33,5 @@ def make_host_mesh(data: int = 1, model: int = 1):
     if len(devices) < need:
         raise RuntimeError(f"need {need} devices, have {len(devices)}")
     return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
                          devices=devices[:need])

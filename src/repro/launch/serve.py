@@ -54,6 +54,7 @@ import numpy as np
 from repro.configs import get_arch, smoke_config
 from repro.launch.engine_args import add_engine_args, engine_config_from_args
 from repro.models.model import Model
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def warm_tile_cache(cfg, *, slots: int, prompt_lens: list[int],
@@ -204,6 +205,7 @@ def main(argv=None) -> int:
             "every architecture (dense/MoE/SWA/RWKV/Mamba/hybrid/VLM) now "
             "serves through the PagedEngine's uniform LayerState tree "
             "(repro.serving.engine; DESIGN.md §10).  Just drop the flag.")
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:
@@ -274,6 +276,9 @@ def main(argv=None) -> int:
         print(f"req {rid}: {done[rid][:8]}...")
     expected = args.requests * max(1, args.repeat)
     print(f"served {len(done)}/{expected} requests")
+    # a failed, timed-out or rejected request fails the run, whatever the
+    # verify replays below find about the requests that completed
+    status = 1 if len(done) < expected else 0
     if args.verify_speculate:
         # replay the exact submissions through a fresh engine with
         # speculation off: accepted drafts must reproduce the greedy chain
@@ -321,7 +326,7 @@ def main(argv=None) -> int:
             return 1
         print(f"fault token-identity: ok ({len(done)}/{len(subs)} "
               f"completed, {len(subs) - len(done)} faulted)")
-    return 0
+    return status
 
 
 if __name__ == "__main__":

@@ -16,13 +16,14 @@ real pods).  Demonstrates the full fault-tolerance story end-to-end:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import sys
 import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import sharding as Sh
 from repro.checkpoint import checkpoint as ckpt
@@ -32,8 +33,62 @@ from repro.launch import steps as S
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import Model
 from repro.optim.adamw import AdamW, cosine_schedule
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault_tolerance import (Heartbeat, StragglerDetector,
                                            Supervisor)
+
+
+@dataclasses.dataclass
+class Trainer:
+    """The trainer's state and jitted step on one mesh (None: one device).
+
+    With a mesh, parameters and optimizer state are made under ``jit``
+    straight into their shardings, so no device ever holds the whole set.
+    """
+    model: Model
+    opt: AdamW
+    mesh: object = None
+    rules: dict | None = None
+    microbatches: int = 1
+    remat: str = "none"
+
+    def __post_init__(self):
+        self.jit_step = jax.jit(
+            S.make_train_step(self.model, self.opt,
+                              num_microbatches=self.microbatches,
+                              remat=self.remat),
+            donate_argnums=(0, 1))
+
+    def shardings(self):
+        """(params, opt state) sharding trees, or (None, None) unmeshed."""
+        if self.mesh is None:
+            return None, None
+        rep = NamedSharding(self.mesh, PartitionSpec())
+        of = lambda specs: jax.tree.map(
+            lambda s: rep if s.sharding is None else s.sharding, specs)
+        return (of(S.sharded_param_specs(self.model, self.mesh, self.rules)),
+                of(S.sharded_opt_specs(self.model, self.opt, self.mesh,
+                                       self.rules)))
+
+    def init(self, key):
+        pshard, oshard = self.shardings()
+        params = jax.jit(self.model.init, out_shardings=pshard)(key)
+        return params, jax.jit(self.opt.init, out_shardings=oshard)(params)
+
+    def place(self, params, ostate):
+        """Host trees (a restored checkpoint) onto the devices."""
+        pshard, oshard = self.shardings()
+        if pshard is None:
+            return jax.tree.map(jnp.asarray, (params, ostate))
+        return jax.device_put(params, pshard), jax.device_put(ostate, oshard)
+
+    def step(self, params, ostate, batch):
+        with Sh.use_mesh_and_rules(self.mesh, self.rules):
+            return self.jit_step(params, ostate, batch)
+
+    def lower(self, params, ostate, batch):
+        with Sh.use_mesh_and_rules(self.mesh, self.rules):
+            return self.jit_step.lower(params, ostate, batch)
 
 
 def main(argv=None) -> int:
@@ -60,6 +115,7 @@ def main(argv=None) -> int:
                    help="tile-plan cache file (also: $KRAKEN_TILE_CACHE); "
                         "without --autotune, replays it read-only")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:
@@ -83,10 +139,8 @@ def main(argv=None) -> int:
     mesh = make_host_mesh(dm, tm) if dm * tm > 1 else None
     rules = Sh.RULES_SINGLE_POD if mesh else None
 
-    step_fn_inner = S.make_train_step(model, opt,
-                                      num_microbatches=args.microbatches,
-                                      remat=args.remat)
-    jit_step = jax.jit(step_fn_inner, donate_argnums=(0, 1))
+    trainer = Trainer(model, opt, mesh=mesh, rules=rules,
+                      microbatches=args.microbatches, remat=args.remat)
 
     hb = Heartbeat(os.path.join(args.ckpt_dir, "heartbeat.json"), interval_s=5)
     straggler = StragglerDetector()
@@ -94,9 +148,8 @@ def main(argv=None) -> int:
     injected = {"done": False}
 
     def make_state():
-        params = model.init(jax.random.key(0))
-        return {"params": params, "opt": opt.init(params),
-                "pipe": PipelineState(0)}
+        params, ostate = trainer.init(jax.random.key(0))
+        return {"params": params, "opt": ostate, "pipe": PipelineState(0)}
 
     def run_one(state, step):
         if step == args.inject_failure_at and not injected["done"]:
@@ -104,8 +157,8 @@ def main(argv=None) -> int:
             raise RuntimeError("injected failure (test)")
         batch_np, pstate = pipe(state["pipe"])
         batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
-        with Sh.use_mesh_and_rules(mesh, rules):
-            params, ostate, metrics = jit_step(state["params"], state["opt"], batch)
+        params, ostate, metrics = trainer.step(state["params"], state["opt"],
+                                               batch)
         if step % args.log_every == 0:
             print(f"step {step:5d} loss {float(metrics['loss']):.4f} "
                   f"ce {float(metrics['ce']):.4f} "
@@ -131,9 +184,9 @@ def main(argv=None) -> int:
         specs = {"params": model.param_specs(),
                  "opt": opt.state_specs(model.param_specs())}
         tree, step, extra = ckpt.restore(args.ckpt_dir, specs)
-        tree = jax.tree.map(jnp.asarray, tree)
+        params, ostate = trainer.place(tree["params"], tree["opt"])
         print(f"[restore] resumed from step {step}")
-        return ({"params": tree["params"], "opt": tree["opt"],
+        return ({"params": params, "opt": ostate,
                  "pipe": PipelineState(extra["pipe_step"])}, step)
 
     sup = Supervisor(make_state=make_state, step_fn=run_one,

@@ -101,10 +101,16 @@ def sinusoidal_pos_emb(positions: jax.Array, d_model: int) -> jax.Array:
 # The uniform-GEMM dense layer
 # ---------------------------------------------------------------------------
 
+def _use_kernels() -> bool:
+    """Pallas kernels run on a TPU outside a multi-device mesh: XLA cannot
+    partition a Mosaic kernel, so under a mesh the XLA ops stay."""
+    return jax.default_backend() == "tpu" and not sharding.partitioned()
+
+
 def dense(x: jax.Array, w: jax.Array, *, bias: jax.Array | None = None,
           activation: str | None = None) -> jax.Array:
     """x: [..., K] @ w: [K, N].  Routes through the uniform dataflow."""
-    if jax.default_backend() == "tpu":
+    if _use_kernels():
         lead = x.shape[:-1]
         out = ops.kraken_matmul(x.reshape(-1, x.shape[-1]), w, bias=bias,
                                 activation=activation, use_pallas=True)
@@ -328,23 +334,12 @@ def _gqa_sdpa_context_parallel(q, k, v, *, window: int, q_pos, kv_pos,
         b, kvh, g, s, d = out.shape
         return out.reshape(b, kvh * g, s, d).astype(ql.dtype)
 
-    f = _shard_map_compat(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec), P(bspec, None, axis), P(bspec, None, axis),
                   P(), P(axis)),
-        out_specs=P(bspec))
+        out_specs=P(bspec), check_vma=False)
     return f(q, k, v, q_pos, kv_pos)
-
-
-def _shard_map_compat(body, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions: jax.shard_map(check_vma=) on >= 0.5,
-    jax.experimental.shard_map.shard_map(check_rep=) on 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
 
 
 def _context_parallel_axis(skv: int) -> str | None:
@@ -774,7 +769,7 @@ def attention(cfg, params: Params, prefix: str, x: jax.Array, *,
     elif kv_x is not None:
         out = _gqa_sdpa(q, k, v, mask_mode="none", window=0,
                         q_pos=positions, kv_pos=jnp.arange(k.shape[2]))
-    elif window and jax.default_backend() == "tpu" and x.shape[1] % 128 == 0:
+    elif window and _use_kernels() and x.shape[1] % 128 == 0:
         out = ops.swa_attention(q, k, v, window=window, use_pallas=True)
     else:
         out = _gqa_sdpa(q, k, v, mask_mode="causal" if causal else "none",

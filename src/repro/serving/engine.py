@@ -78,6 +78,12 @@ class JitCounter:
         self.calls += 1
         return self._jit(*args)
 
+    def lower(self, *args):
+        """Lower the program for ``args`` ahead of a call (``.compile()``
+        it to time the compile or read the compiled HLO); the call with
+        the same arguments then reuses that compile."""
+        return self._jit.lower(*args)
+
     @property
     def retraces(self) -> int:
         return len(self.signatures)

@@ -79,6 +79,13 @@ def current() -> dict | None:
     return stack[-1] if stack else None
 
 
+def partitioned() -> bool:
+    """Whether model code is being traced under a multi-device mesh, where
+    XLA partitions every op (and cannot partition a Pallas kernel)."""
+    c = current()
+    return c is not None and c["mesh"] is not None and c["mesh"].size > 1
+
+
 def dropped_axes() -> list[tuple]:
     c = current()
     return list(c["dropped"]) if c else []

@@ -105,23 +105,14 @@ def test_compressed_allreduce_under_shard_map():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np, json, functools
         from jax.sharding import PartitionSpec as P
-        try:                                   # jax >= 0.5
-            from jax import shard_map
-            sm_kw = {'check_vma': False}
-        except ImportError:                    # jax 0.4.x
-            from jax.experimental.shard_map import shard_map
-            sm_kw = {'check_rep': False}
-        try:
-            mesh = jax.make_mesh((8,), ('pod',),
-                                 axis_types=(jax.sharding.AxisType.Auto,))
-        except (AttributeError, TypeError):    # pre-AxisType jax
-            mesh = jax.make_mesh((8,), ('pod',))
+        mesh = jax.make_mesh((8,), ('pod',),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         from repro.optim import compress
         g = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4) / 7.0
         state = compress.init_state({'w': g[0]})
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=(P('pod'),),
-                           out_specs=P('pod'), **sm_kw)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P('pod'),),
+                           out_specs=P('pod'), check_vma=False)
         def sync(local_g):
             grads = {'w': local_g[0]}
             st = compress.init_state(grads)
@@ -175,11 +166,8 @@ def test_context_parallel_attention_matches_plain():
         from repro import sharding as Sh
         from repro.models import layers as L
 
-        try:
-            mesh = jax.make_mesh((2, 4), ("data", "model"),
-                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
-        except (AttributeError, TypeError):    # pre-AxisType jax
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rules = dict(Sh.RULES_SINGLE_POD, attn_context_parallel="model")
         rng = np.random.default_rng(0)
         B, H, KV, S, D = 2, 6, 2, 4096, 16   # H=6 % model=4 != 0
