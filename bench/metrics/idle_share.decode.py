@@ -1,0 +1,9 @@
+"""Device: the share of the traced window in which no operation ran on
+the chip (one minus the union of device-op intervals, asynchronous copies
+included), in the cells that report ``output_tok_s``."""
+
+from bench.readings import idle_share
+
+
+def read(run):
+    return idle_share(run)
