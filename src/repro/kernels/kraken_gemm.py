@@ -122,11 +122,11 @@ def kraken_gemm(a: jnp.ndarray, b: jnp.ndarray, *,
     if schedule == "weight_stationary":
         return pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_spec,
-            out_shape=out_shape, interpret=interpret,
+            out_shape=out_shape, interpret=interpret, name="kraken_gemm",
         )(*operands)
     import jax.experimental.pallas.tpu as pltpu  # noqa: deferred import
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_spec,
-        out_shape=out_shape, interpret=interpret,
+        out_shape=out_shape, interpret=interpret, name="kraken_gemm",
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
     )(*operands)

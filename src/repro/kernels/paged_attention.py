@@ -329,6 +329,6 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                           scale=1.0 / (d ** 0.5), quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="paged_decode_attention",
     )(tbl, qpos_arr, *args)
     return out.reshape(b, h, d)

@@ -43,6 +43,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.models.model import Model
 from repro.runtime.fault_tolerance import Heartbeat, StragglerDetector
@@ -64,19 +65,32 @@ class JitCounter:
     ``retraces`` is the compilation count the zero-retrace assertions key
     on; ``cache_size`` cross-checks against jit's own compiled-program
     cache when the running jax exposes it.
+
+    Every call is an ``engine.dispatch.<name>`` span on the profiler's
+    clock, from the signature check until the call returns; a call with a
+    new signature holds an ``engine.compile.<name>`` span inside it, so a
+    compile in a traced window is named.
     """
 
-    def __init__(self, fn, *, donate_argnums=()):
+    def __init__(self, fn, *, name: str, donate_argnums=()):
         self._jit = jax.jit(fn, donate_argnums=donate_argnums)
+        self.name = name
+        self._dispatch_span = f"engine.dispatch.{name}"
+        self._compile_span = f"engine.compile.{name}"
         self.signatures: set = set()
         self.calls = 0
 
     def __call__(self, *args):
-        self.signatures.add(tuple(
-            (tuple(leaf.shape), str(leaf.dtype))
-            for leaf in jax.tree.leaves(args) if hasattr(leaf, "shape")))
-        self.calls += 1
-        return self._jit(*args)
+        with TraceAnnotation(self._dispatch_span):
+            sig = tuple((tuple(leaf.shape), str(leaf.dtype))
+                        for leaf in jax.tree.leaves(args)
+                        if hasattr(leaf, "shape"))
+            self.calls += 1
+            if sig in self.signatures:
+                return self._jit(*args)
+            self.signatures.add(sig)
+            with TraceAnnotation(self._compile_span):
+                return self._jit(*args)
 
     def lower(self, *args):
         """Lower the program for ``args`` ahead of a call (``.compile()``
@@ -454,9 +468,11 @@ class PagedEngine:
 
         # ``_prefill`` is the mixed-step program (the only one that ever
         # prefills); the names keep the stats/CLI surface stable
-        self._prefill = JitCounter(mixed_fn, donate_argnums=(1,))
-        self._decode = JitCounter(decode_fn, donate_argnums=(1,))
-        self._reset = JitCounter(reset_fn, donate_argnums=(0,))
+        self._prefill = JitCounter(mixed_fn, name="mixed",
+                                   donate_argnums=(1,))
+        self._decode = JitCounter(decode_fn, name="decode",
+                                  donate_argnums=(1,))
+        self._reset = JitCounter(reset_fn, name="reset", donate_argnums=(0,))
 
         # --- per-slot host state ------------------------------------------
         self.active: list[ServeRequest | None] = [None] * slots
@@ -470,6 +486,8 @@ class PagedEngine:
         #                             run_until_idle while everything queued
         #                             is backing off: no program, no step)
         self.steps = 0              # programs run (mixed + pure decode)
+        self.live_rows = 0          # live decode rows + the prefill row,
+        #                             summed over programs run
         self.decode_steps = 0       # steps that advanced >= 1 decode slot
         self._issued = 0            # real tokens issued across all steps
         self._max_stall = 0         # worst decode gap observed, in steps
@@ -551,8 +569,45 @@ class PagedEngine:
         budget first) when a chunk fits, the pure fused-kernel decode
         step otherwise.  A fault injected at the pre-program seam is
         handed to the watchdog's recovery policy instead of crashing
-        the batch (DESIGN.md §14)."""
+        the batch (DESIGN.md §14).
+
+        Each tick is one ``engine.step`` span on the profiler's clock
+        (``step_num`` = ``ticks``), its parts the child spans
+        ``engine.schedule``, ``engine.inputs``, ``engine.dispatch.*``,
+        ``engine.sample``, ``engine.advance`` and ``engine.push_tables``
+        (``bench/engine_trace.py`` splits the chip's idle time by them)."""
         self.ticks += 1
+        with StepTraceAnnotation("engine.step", step_num=self.ticks):
+            with TraceAnnotation("engine.schedule"):
+                picked = self._schedule()
+            if picked is None:
+                return
+            dec, pf = picked
+            t0 = time.perf_counter()
+            self.steps += 1
+            self.live_rows += len(dec) + (pf is not None)
+            if pf is not None or (self.speculate and dec):
+                # with speculation on, decode always rides the mixed
+                # program (a speculating slot is a multi-token chunk;
+                # verify is the chunk step) — the pure decode program
+                # simply goes unused, so the engine still compiles at
+                # most three programs
+                self._mixed_step(dec, pf)
+            else:
+                self._decode_step(dec)
+            dt = time.perf_counter() - t0
+            self.straggler.record(dt)
+            if self.heartbeat is not None:
+                self.heartbeat.beat(self.ticks, steps=self.steps,
+                                    queued=len(self.sched.queue),
+                                    running=len(self.sched.running),
+                                    done=len(self.sched.done))
+
+    def _schedule(self) -> tuple[list[int], int | None] | None:
+        """Expire, sweep and admit, then pick this tick's rows: the live
+        decode slots and the prefill slot whose chunk fits the budget.
+        None when no program runs this tick (nothing live, or a fault at
+        the pre-program seam was recovered)."""
         if self.faults is not None:
             self.faults.on_tick(self)
         self._expire()
@@ -578,7 +633,7 @@ class PagedEngine:
             if committed + remaining > self.step_budget:
                 pf = None
         if not dec and pf is None:
-            return
+            return None
         if self.faults is not None:
             # the pre-program seam: slots are selected but the jitted call
             # has not consumed (donated) the pools, so a fault raised here
@@ -587,24 +642,8 @@ class PagedEngine:
                 self.faults.before_program(self)
             except Exception as e:   # noqa: BLE001 — any injected fault
                 self._recover(e, dec, pf)
-                return
-        t0 = time.perf_counter()
-        self.steps += 1
-        if pf is not None or (self.speculate and dec):
-            # with speculation on, decode always rides the mixed program
-            # (a speculating slot is a multi-token chunk; verify is the
-            # chunk step) — the pure decode program simply goes unused,
-            # so the engine still compiles at most three programs
-            self._mixed_step(dec, pf)
-        else:
-            self._decode_step(dec)
-        dt = time.perf_counter() - t0
-        self.straggler.record(dt)
-        if self.heartbeat is not None:
-            self.heartbeat.beat(self.ticks, steps=self.steps,
-                                queued=len(self.sched.queue),
-                                running=len(self.sched.running),
-                                done=len(self.sched.done))
+                return None
+        return dec, pf
 
     # ------------------------------------------------- failure edges (§14)
     def _expire(self) -> None:
@@ -689,19 +728,21 @@ class PagedEngine:
             # pages can never starve a resume.
             if not self._can_admit_head(None):
                 return
-            self.sched.pop(head, free[0])
-            try:
-                self._resume(head)
-            except SwapIntegrityError as e:
-                # a corrupted/truncated host snapshot is rejected before
-                # any device write: undo the claim (slot, pages, tables)
-                # and fail the request — never resume garbage
-                slot = head.slot
-                self.active[slot] = None
-                self.state.release(slot)
-                self._push_tables()
-                self.sched.terminate(head, FAILED, str(e))
-                self.swap_rejects += 1
+            with TraceAnnotation("engine.admit", rid=head.rid, slot=free[0]):
+                self.sched.pop(head, free[0])
+                try:
+                    self._resume(head)
+                except SwapIntegrityError as e:
+                    # a corrupted/truncated host snapshot is rejected
+                    # before any device write: undo the claim (slot,
+                    # pages, tables) and fail the request — never resume
+                    # garbage
+                    slot = head.slot
+                    self.active[slot] = None
+                    self.state.release(slot)
+                    self._push_tables()
+                    self.sched.terminate(head, FAILED, str(e))
+                    self.swap_rejects += 1
             return
         # one cache lookup per admission attempt, on the head only —
         # match takes no references, so a rejected admission drops it cold
@@ -725,7 +766,12 @@ class PagedEngine:
             return
         if not self._can_admit_head(hit):
             return
-        req = self.sched.pop(head, free[0])
+        with TraceAnnotation("engine.admit", rid=head.rid, slot=free[0]):
+            self._claim(self.sched.pop(head, free[0]), hit)
+
+    def _claim(self, req: ServeRequest, hit: PrefixHit | None) -> None:
+        """Seat a popped request in its slot: prefix-cache pages mapped,
+        freed-slot reset (and the CoW copy) run, tables pushed."""
         # a cache hit admits straight to PREFILLING(k/K): the shared pages
         # map into the slot's leading logical rows and prefill resumes at
         # the page boundary (full hits recompute only the last token for
@@ -856,12 +902,58 @@ class PagedEngine:
         self.resumes += 1
 
     def _mixed_step(self, dec: list[int], pf: int | None) -> None:
+        req = self.active[pf] if pf is not None else None
+        n = min(self.chunk, req.prompt_len - req.prefill_pos) \
+            if req is not None else 0
+        with TraceAnnotation("engine.inputs"):
+            tokens, positions, lengths, meta, snaps = \
+                self._pack_mixed(dec, pf, n)
+            args = (jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(lengths))
+        last, greedy, self.pools = self._prefill(self.params, self.pools,
+                                                 *args)
+        self._issued += int(sum(lengths[i] for i in dec)) + n
+        self._prefill_tok += n
+        nxt = self._sample(last)
+        with TraceAnnotation("engine.advance"):
+            if meta:
+                finished = self._advance_speculative(
+                    dec, np.asarray(greedy), meta, snaps)
+            else:
+                finished = self._advance_decode(dec, nxt)
+            if pf is not None:
+                req.prefill_pos += n
+                req.chunks_done += 1
+                if req.prefill_pos >= req.prompt_len:
+                    # prefill complete: register the prompt's full page
+                    # chunks under the cache chain (already-cached chunks
+                    # just touch LRU, so a CoW fork's private copy never
+                    # displaces the original).  Only the *prompt* —
+                    # committed tokens — ever reaches the chain; draft
+                    # tokens live in decode rows and are structurally
+                    # invisible here (DESIGN.md §15).
+                    if self.prefix_cache is not None:
+                        self.prefix_cache.insert(
+                            req.prompt,
+                            self._cache_alloc.slot_pages(req.slot))
+                    # last chunk: its top-row logits are the first token
+                    req.state = RUNNING
+                    req.out.append(int(nxt[pf]))
+                    req.t_first = self.sched.clock()
+                    self._cur[pf, 0] = int(nxt[pf])
+                    self._pos[pf] = req.prompt_len
+                    self._emit_step[pf] = self.steps
+                    if len(req.out) >= req.max_new:  # max_new=1: done now
+                        self._finish(pf)
+                        finished += 1
+        if finished:
+            self._push_tables()
+
+    def _pack_mixed(self, dec: list[int], pf: int | None, n: int):
+        """The mixed program's host inputs: ``[slots, chunk]`` tokens and
+        positions, per-row lengths, and under speculation each row's
+        (pending, drafts) and the recurrent snapshots to roll back to."""
         w = self.chunk
-        req = None
-        n = 0
-        if pf is not None:
-            req = self.active[pf]
-            n = min(w, req.prompt_len - req.prefill_pos)
         tokens = np.zeros((self.slots, w), np.int32)
         positions = np.zeros((self.slots, w), np.int32)
         lengths = np.zeros((self.slots,), np.int32)
@@ -897,46 +989,12 @@ class PagedEngine:
                 positions[i] = self._pos[i] + ar
                 lengths[i] = 1
         if pf is not None:
+            req = self.active[pf]
             start = req.prefill_pos
             tokens[pf, :n] = req.prompt[start:start + n]
             positions[pf] = start + ar
             lengths[pf] = n
-        last, greedy, self.pools = self._prefill(
-            self.params, self.pools, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(lengths))
-        self._issued += int(sum(lengths[i] for i in dec)) + n
-        self._prefill_tok += n
-        nxt = self._sample(last)
-        if meta:
-            finished = self._advance_speculative(dec, np.asarray(greedy),
-                                                 meta, snaps)
-        else:
-            finished = self._advance_decode(dec, nxt)
-        if pf is not None:
-            req.prefill_pos += n
-            req.chunks_done += 1
-            if req.prefill_pos >= req.prompt_len:
-                # prefill complete: register the prompt's full page chunks
-                # under the cache chain (already-cached chunks just touch
-                # LRU, so a CoW fork's private copy never displaces the
-                # original).  Only the *prompt* — committed tokens — ever
-                # reaches the chain; draft tokens live in decode rows and
-                # are structurally invisible here (DESIGN.md §15).
-                if self.prefix_cache is not None:
-                    self.prefix_cache.insert(
-                        req.prompt, self._cache_alloc.slot_pages(req.slot))
-                # last chunk: its top-row logits are the first token
-                req.state = RUNNING
-                req.out.append(int(nxt[pf]))
-                req.t_first = self.sched.clock()
-                self._cur[pf, 0] = int(nxt[pf])
-                self._pos[pf] = req.prompt_len
-                self._emit_step[pf] = self.steps
-                if len(req.out) >= req.max_new:  # max_new=1: done at prefill
-                    self._finish(pf)
-                    finished += 1
-        if finished:
-            self._push_tables()
+        return tokens, positions, lengths, meta, snaps
 
     # ------------------------------------------- speculative decode (§15)
     def _n_pending(self, i: int) -> int:
@@ -1030,14 +1088,17 @@ class PagedEngine:
         return finished
 
     def _decode_step(self, dec: list[int]) -> None:
-        live = np.zeros((self.slots,), np.int32)
-        live[dec] = 1
-        logits, self.pools = self._decode(
-            self.params, self.pools, jnp.asarray(self._cur),
-            jnp.asarray(self._pos), jnp.asarray(live))
+        with TraceAnnotation("engine.inputs"):
+            live = np.zeros((self.slots,), np.int32)
+            live[dec] = 1
+            args = (jnp.asarray(self._cur), jnp.asarray(self._pos),
+                    jnp.asarray(live))
+        logits, self.pools = self._decode(self.params, self.pools, *args)
         self._issued += len(dec)
         nxt = self._sample(logits)
-        if self._advance_decode(dec, nxt):
+        with TraceAnnotation("engine.advance"):
+            finished = self._advance_decode(dec, nxt)
+        if finished:
             # sentinel the freed page-table rows on device before the next
             # step: an idle slot's KV writes must drop, not land in pages
             # a later request may own.  (Recurrent slot-row states need no
@@ -1070,19 +1131,25 @@ class PagedEngine:
         """Retire a slot (host bookkeeping only — the caller pushes the
         updated tables to device once per wave)."""
         req = self.active[slot]
-        self.active[slot] = None
-        self.sched.complete(req)
-        self.state.release(slot)
+        with TraceAnnotation("engine.finish", rid=req.rid):
+            self.active[slot] = None
+            self.sched.complete(req)
+            self.state.release(slot)
 
     def _push_tables(self) -> None:
-        self.pools = self.state.push_tables(self.pools)
+        with TraceAnnotation("engine.push_tables"):
+            self.pools = self.state.push_tables(self.pools)
 
     def _sample(self, logits) -> np.ndarray:
-        if self.temperature > 0:
-            self._key, sub = jax.random.split(self._key)
-            return np.asarray(jax.random.categorical(
-                sub, logits.astype(jnp.float32) / self.temperature, axis=-1))
-        return np.asarray(jnp.argmax(logits, axis=-1))
+        """The next token of every row: the argmax or categorical dispatch
+        and the blocking host read of its result."""
+        with TraceAnnotation("engine.sample"):
+            if self.temperature > 0:
+                self._key, sub = jax.random.split(self._key)
+                return np.asarray(jax.random.categorical(
+                    sub, logits.astype(jnp.float32) / self.temperature,
+                    axis=-1))
+            return np.asarray(jnp.argmax(logits, axis=-1))
 
     # ------------------------------------------------------------ metrics
     @property
@@ -1096,6 +1163,7 @@ class PagedEngine:
             "prefill_retraces": self._prefill.retraces,
             "prefill_cache_size": self._prefill.cache_size,
             "steps": self.steps,
+            "live_rows": self.live_rows,
             "decode_steps": self.decode_steps,
             "decode_retraces": self._decode.retraces,
             "decode_kernel": self.decode_kernel,

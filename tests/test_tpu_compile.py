@@ -14,6 +14,7 @@ every test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -113,6 +114,36 @@ def test_paged_decode_compiles(one_chip, page_size, max_len, quantized, ppb):
             k_scale=k_scale, v_scale=v_scale,
             pages_per_block=pages_per_block)
     assert "tpu_custom_call" in _compiled_text(attend, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("kernel", ["kraken_gemm", "paged_decode_attention"])
+def test_main_path_kernels_carry_their_names(one_chip, kernel):
+    """Each of the decode program's Pallas kernels is an HLO instruction
+    named after it, so a profiler trace names its ops by kernel."""
+    kvh, g, d = YI.num_kv_heads, YI.num_heads // YI.num_kv_heads, YI.head_dim
+    mp = MAX_LEN // PAGE_SIZE
+    n_pages = SLOTS * mp + 1
+    if kernel == "kraken_gemm":
+        def fn(a, b):
+            return ops.kraken_matmul(a, b, use_pallas=True, interpret=False,
+                                     tile_mode="model")
+        shapes = [((SLOTS, YI.d_model), jnp.bfloat16),
+                  ((YI.d_model, YI.d_ff), jnp.bfloat16)]
+    else:
+        def fn(q, k, v, pos, table, q_pos):
+            return paged_decode_attention(q, k, v, pos_pages=pos,
+                                          page_table=table, q_pos=q_pos)
+        shapes = [((SLOTS, kvh * g, d), jnp.bfloat16),
+                  ((n_pages, kvh, PAGE_SIZE, d), jnp.bfloat16),
+                  ((n_pages, kvh, PAGE_SIZE, d), jnp.bfloat16),
+                  ((n_pages, PAGE_SIZE), jnp.int32),
+                  ((SLOTS, mp), jnp.int32), ((SLOTS,), jnp.int32)]
+    calls = [line for line in _compiled_text(fn, one_chip, *shapes)
+             .splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        assert re.search(rf"%{kernel}(\.\d+)? = .* custom-call\(", line), \
+            line[:200]
 
 
 @pytest.mark.parametrize("direction", ["up", "down"])
