@@ -12,23 +12,30 @@ read once, page by page, only for the pages a slot actually owns — the
 Kraken lesson (memory traffic decided by the dataflow, not the instruction
 mix) applied to the decode hot loop.
 
-Layout per grid step ``(slot, kv_head, page_block)``:
+Layout per grid step ``(slot, page_block)``:
 
-  q        [1, 1, G, D]     resident across page blocks (output-stationary)
-  k/v      ppb x [1, 1, ps, D]   physical pages, index-mapped via the table
+  q        [1, KV, G, D]    resident across page blocks (output-stationary)
+  k/v      ppb x [1, KV, ps, D]  physical pages, every KV head, index-mapped
   pos      ppb x [1, 1, ps]  absolute position per entry (-2^30 = empty)
-  k/v scale ppb x [1, 1, 1, ps] f32 (int8 pools only; folded into the dots)
-  acc/m/l  VMEM scratch      online-softmax state, G x D
+  k/v scale ppb x [1, KV, 1, ps] f32 (int8 pools only; folded into the dots)
+  acc/m/l  VMEM scratch      online-softmax state, KV x G x D
 
-Positions and scales get a singleton axis so each block's last two dims
-equal the array's (the TPU tiling rule for rows narrower than (8, 128)),
-and every per-entry vector stays a 2-D ``[1, n]`` lane row: the mask and
-the scales are built by concatenating rows along lanes, never 1-D vectors.
+The pool is ``[n_pages, KV, ps, D]``, so every KV head of one page is one
+contiguous block: a step fetches it in one DMA, and the page's position
+row once, for all heads.  The KV heads ride as the batch axis of the two
+dots; the position row and its mask are built once per step and shared by
+every head.  Positions and scales get a singleton axis so each block's last
+two dims equal the array's (the TPU tiling rule for rows narrower than
+(8, 128)), and every per-entry vector stays a lane row: the mask and the
+scales are built by concatenating rows along lanes, never 1-D vectors.
 
 ``pages_per_block`` (ppb) logical pages are fetched per step — the tunable
 the ``op_kind="paged_decode"`` autotuner measures.  Each page is its own
 operand (same pool array, ppb index maps), because a slot's physical pages
 are not contiguous: one BlockSpec cannot describe a multi-page gather.
+The untuned ppb streams a 512-token stripe, lowered where the step's
+double-buffered K and V blocks would outgrow ``KV_BLOCK_VMEM_BYTES``
+(geometries with many KV heads).
 
 Empty-block skip rule: a page is *dead* when its table entry is the
 out-of-bounds sentinel (unallocated slot) or its first logical index lies
@@ -116,10 +123,32 @@ def use_paged_decode_mode(mode: str | None):
         _mode = prev
 
 
-def default_pages_per_block(page_size: int, max_pages: int) -> int:
+KV_BLOCK_VMEM_BYTES = 4 << 20  # a step's K + V blocks, double-buffered
+
+
+def page_block_bytes(page_size: int, *, kv_heads: int, head_dim: int,
+                     itemsize: int) -> int:
+    """VMEM one logical page costs a grid step: its K and V blocks (every
+    KV head), each double-buffered by the pipeline."""
+    return 2 * 2 * kv_heads * page_size * head_dim * itemsize
+
+
+def max_pages_per_block(page_size: int, *, kv_heads: int, head_dim: int,
+                        itemsize: int) -> int:
+    """The largest ppb whose K + V blocks fit ``KV_BLOCK_VMEM_BYTES``."""
+    return max(1, KV_BLOCK_VMEM_BYTES // page_block_bytes(
+        page_size, kv_heads=kv_heads, head_dim=head_dim, itemsize=itemsize))
+
+
+def default_pages_per_block(page_size: int, max_pages: int, *, kv_heads: int,
+                            head_dim: int, itemsize: int) -> int:
     """Untuned ppb: the same ~512-slot KV stripe per grid step that
-    ``decode_attention``'s ``block_s`` default streams."""
-    return max(1, min(max_pages, 512 // max(1, page_size)))
+    ``decode_attention``'s ``block_s`` default streams, bounded by the VMEM
+    budget of a step that holds every KV head of each page."""
+    return max(1, min(max_pages, 512 // max(1, page_size),
+                      max_pages_per_block(page_size, kv_heads=kv_heads,
+                                          head_dim=head_dim,
+                                          itemsize=itemsize)))
 
 
 def resolve_pages_per_block(*, slots: int, logical_len: int, head_dim: int,
@@ -129,12 +158,15 @@ def resolve_pages_per_block(*, slots: int, logical_len: int, head_dim: int,
     """ppb under the process-wide tile policy (mirrors ``choose_tiles``):
     ``model`` -> static default; ``cached`` -> replay a persisted
     ``op_kind="paged_decode"`` winner (key ``m/k/n`` <-
-    slots/logical_len/head_dim, entry validated against ``page_size``) or
-    fall back; ``autotune`` -> measure the miss and persist it."""
+    slots/logical_len/head_dim, entry validated against ``page_size`` and
+    ``kv_heads``) or fall back; ``autotune`` -> measure the miss and
+    persist it."""
     from repro import tuning
     from repro.tuning import cache as tcache
     from repro.tuning.search import lookup_paged_decode
-    default = default_pages_per_block(page_size, max_pages)
+    default = default_pages_per_block(
+        page_size, max_pages, kv_heads=kv_heads, head_dim=head_dim,
+        itemsize=jnp.dtype(dtype_name).itemsize)
     mode = tuning.get_tile_mode()
     if mode == "model":
         return default
@@ -142,7 +174,7 @@ def resolve_pages_per_block(*, slots: int, logical_len: int, head_dim: int,
     key = tcache.cache_key("paged_decode", slots, logical_len, head_dim,
                            dtype_name, tuning.backend_name())
     hit = lookup_paged_decode(cache, key, page_size=page_size,
-                              max_pages=max_pages)
+                              kv_heads=kv_heads, max_pages=max_pages)
     if hit is not None:
         return hit
     if mode == "autotune":
@@ -170,7 +202,7 @@ def _kernel(tbl_ref, qpos_ref, q_ref, *refs, ppb: int, nblk: int,
     o_ref, m_ref, l_ref, acc_ref = refs[n_in:]
 
     b = pl.program_id(0)
-    pb = pl.program_id(2)
+    pb = pl.program_id(1)
 
     @pl.when(pb == 0)
     def _init():
@@ -197,33 +229,34 @@ def _kernel(tbl_ref, qpos_ref, q_ref, *refs, ppb: int, nblk: int,
     # subsumes the page-liveness test and additionally skips blocks whose
     # positions all fell out of the sliding window.  Everything beyond the
     # cheap position row — dequant, concat, both dots — stays inside the
-    # skipped body.
+    # skipped body.  Positions are per page, not per head: one mask serves
+    # every KV head.
     mask = (kv_pos >= 0) & (kv_pos <= q_pos)
     if window:
         mask = mask & (kv_pos > q_pos - window)
 
     @pl.when(jnp.any(mask))
     def _update():
-        k = jnp.concatenate([r[0, 0] for r in k_refs], axis=0)  # [ppb*ps, D]
-        v = jnp.concatenate([r[0, 0] for r in v_refs], axis=0)
-        q = q_ref[0, 0]                                   # [G, D]
+        k = jnp.concatenate([r[0] for r in k_refs], axis=1)  # [KV, S, D]
+        v = jnp.concatenate([r[0] for r in v_refs], axis=1)
+        q = q_ref[0]                                      # [KV, G, D]
         if quantized:
             # dequant folded into the dots: a per-entry scale multiplies
-            # column s of q.k^T and row s of v, i.e. one lane row each
-            k_sc = jnp.concatenate([r[0, 0] for r in ksc_refs], axis=1)
-            v_sc = jnp.concatenate([r[0, 0] for r in vsc_refs], axis=1)
+            # column s of q.k^T and row s of v, i.e. one lane row per head
+            k_sc = jnp.concatenate([r[0] for r in ksc_refs], axis=2)
+            v_sc = jnp.concatenate([r[0] for r in vsc_refs], axis=2)
             q = q.astype(jnp.float32)
             k = k.astype(jnp.float32)
             v = v.astype(jnp.float32)
         logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [G, ppb*ps]
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)           # [KV, G, S]
         if quantized:
-            logits = logits * k_sc
+            logits = logits * k_sc                        # [KV, 1, S]
         logits = logits * scale
-        masked = jnp.where(mask, logits, -1e30)
+        masked = jnp.where(mask[None], logits, -1e30)
         m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
-        m_cur = jnp.max(masked, axis=-1, keepdims=True)   # [G, 1]
+        m_cur = jnp.max(masked, axis=-1, keepdims=True)   # [KV, G, 1]
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(masked - m_new)
@@ -231,15 +264,15 @@ def _kernel(tbl_ref, qpos_ref, q_ref, *refs, ppb: int, nblk: int,
         if quantized:
             p = p * v_sc
         acc_ref[...] = acc_prev * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)           # [KV, G, D]
         m_ref[...] = m_new
 
     @pl.when(pb == nblk - 1)
     def _done():
         l = l_ref[...]
-        o_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+                    ).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -258,13 +291,15 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     [B, max_pages] physical page per (slot, logical page), out-of-bounds
     sentinel ``n_pages`` for unallocated rows; q_pos: [B] per-slot
     positions.  Returns [B, H, D]; slots with no live page return zeros.
+    ``pages_per_block``: logical pages per grid step, every KV head of each.
     """
     b, h, d = q.shape
     n_pages, kvh, ps, _ = k_pages.shape
     mp = page_table.shape[1]
     g = h // kvh
     quantized = k_scale is not None
-    ppb = pages_per_block or default_pages_per_block(ps, mp)
+    ppb = pages_per_block or default_pages_per_block(
+        ps, mp, kv_heads=kvh, head_dim=d, itemsize=k_pages.dtype.itemsize)
     ppb = max(1, min(int(ppb), mp))
     nblk = ceil_div(mp, ppb)
     tbl = jnp.asarray(page_table, jnp.int32)
@@ -279,32 +314,28 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     # array's, the TPU tiling rule for rows narrower than (8, 128)
     pos_pages = jnp.asarray(pos_pages, jnp.int32).reshape(n_pages, 1, ps)
 
-    def page_map(j, trail):
-        def m(bi, hi, pb, tbl, qp):
+    def page_map(j, ndim):
+        def m(bi, pb, tbl, qp):
             pid = tbl[bi, pb * ppb + j]
             live = (pid < n_pages) & ((pb * ppb + j) * ps <= qp[bi])
             # dead pages fetch physical page 0; consecutive dead blocks then
             # keep the block index unchanged and the pipeline skips the DMA
             idx = jnp.where(live, pid, 0)
-            return (idx,) + trail(hi)
+            return (idx,) + (0,) * (ndim - 1)
         return m
 
-    kv_trail = lambda hi: (hi, 0, 0)
-    pos_trail = lambda hi: (0, 0)
-    sc_trail = lambda hi: (hi, 0, 0)
-
-    in_specs = [pl.BlockSpec((1, 1, g, d),
-                             lambda bi, hi, pb, tbl, qp: (bi, hi, 0, 0))]
-    in_specs += [pl.BlockSpec((1, 1, ps, d), page_map(j, kv_trail))
+    slot_map = lambda bi, pb, tbl, qp: (bi, 0, 0, 0)
+    in_specs = [pl.BlockSpec((1, kvh, g, d), slot_map)]
+    in_specs += [pl.BlockSpec((1, kvh, ps, d), page_map(j, 4))
                  for j in range(ppb)]
-    in_specs += [pl.BlockSpec((1, 1, ps, d), page_map(j, kv_trail))
+    in_specs += [pl.BlockSpec((1, kvh, ps, d), page_map(j, 4))
                  for j in range(ppb)]
-    in_specs += [pl.BlockSpec((1, 1, ps), page_map(j, pos_trail))
+    in_specs += [pl.BlockSpec((1, 1, ps), page_map(j, 3))
                  for j in range(ppb)]
     args = ([q.reshape(b, kvh, g, d)] + [k_pages] * ppb + [v_pages] * ppb
             + [pos_pages] * ppb)
     if quantized:
-        sc_spec = [pl.BlockSpec((1, 1, 1, ps), page_map(j, sc_trail))
+        sc_spec = [pl.BlockSpec((1, kvh, 1, ps), page_map(j, 4))
                    for j in range(ppb)]
         in_specs += sc_spec + sc_spec
         args += ([k_scale.reshape(n_pages, kvh, 1, ps)] * ppb
@@ -313,14 +344,13 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     from jax.experimental.pallas import tpu as pltpu
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, nblk),
+        grid=(b, nblk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bi, hi, pb, tbl, qp: (bi, hi, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, g, d), slot_map),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((kvh, g, 1), jnp.float32),
+            pltpu.VMEM((kvh, g, 1), jnp.float32),
+            pltpu.VMEM((kvh, g, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -96,7 +96,8 @@ def warm_tile_cache(cfg, *, slots: int, prompt_lens: list[int],
                                    pool_dtype, tuning.backend_name())
             mp = max(1, logical // page_size)
             was_hit = tuning.lookup_paged_decode(
-                cache, key, page_size=page_size, max_pages=mp,
+                cache, key, page_size=page_size,
+                kv_heads=cfg.num_kv_heads, max_pages=mp,
                 count=False) is not None
             ppb = tuning.autotune_paged_decode(
                 g_slots, logical, head_dim, page_size=page_size,
@@ -104,7 +105,8 @@ def warm_tile_cache(cfg, *, slots: int, prompt_lens: list[int],
                 window=window, dtype_name=pool_dtype, cache=cache, log=log)
             # a cell the interpret-mode cap skipped persists nothing
             tuned = tuning.lookup_paged_decode(
-                cache, key, page_size=page_size, max_pages=mp,
+                cache, key, page_size=page_size,
+                kv_heads=cfg.num_kv_heads, max_pages=mp,
                 count=False) is not None
             status = "hit" if was_hit else "tuned" if tuned else "skipped"
             log(f"tile-cache {status:<7} "

@@ -192,34 +192,44 @@ def _same_plan(a: TileConfig, b: TileConfig) -> bool:
     return (a.bm, a.bk, a.bn, a.schedule) == (b.bm, b.bk, b.bn, b.schedule)
 
 
-def paged_decode_candidates(page_size: int, max_pages: int) -> list[int]:
+def paged_decode_candidates(page_size: int, max_pages: int, *,
+                            kv_heads: int, head_dim: int,
+                            itemsize: int) -> list[int]:
     """The ``pages_per_block`` lattice for the fused paged-decode kernel:
     every power of two up to the whole table, the table itself, and the
-    static default."""
-    from repro.kernels.paged_attention import default_pages_per_block
-    cands = {max_pages, default_pages_per_block(page_size, max_pages)}
+    static default — each within the kernel's VMEM budget for a step that
+    holds every KV head of its pages."""
+    from repro.kernels.paged_attention import (default_pages_per_block,
+                                               max_pages_per_block)
+    geom = dict(kv_heads=kv_heads, head_dim=head_dim, itemsize=itemsize)
+    cap = min(max_pages, max_pages_per_block(page_size, **geom))
+    cands = {max_pages, default_pages_per_block(page_size, max_pages, **geom)}
     ppb = 1
     while ppb <= max_pages:
         cands.add(ppb)
         ppb *= 2
-    return sorted(c for c in cands if 1 <= c <= max_pages)
+    return sorted(c for c in cands if 1 <= c <= cap)
 
 
 def lookup_paged_decode(cache: tcache.TileCache, key: str, *,
-                        page_size: int, max_pages: int,
+                        page_size: int, kv_heads: int, max_pages: int,
                         count: bool = True) -> int | None:
     """A validated ``paged_decode`` cache hit, or None.
 
     The key's ``m/k/n`` (slots/logical_len/head_dim) under-determines the
     cell: the same logical length can be built from different page sizes,
     and a ``pages_per_block`` tuned for 8-token pages means nothing for
-    16-token ones.  The entry records its ``page_size``; a mismatch is a
-    miss (autotune then re-measures for the layout actually being served).
+    16-token ones.  The entry records its ``page_size`` and ``kv_heads``
+    (a step fetches every KV head of its pages, so the head count sets the
+    bytes a ppb moves); a mismatch, or an entry without them (one tuned
+    for a grid step per KV head), is a miss, and autotune then re-measures
+    for the layout actually being served.
     ``count=False`` peeks without touching the hit/miss counters (status
     reporting around a call that will do its own counted lookup).
     """
     entry = cache.peek(key)
-    if not entry or entry.get("page_size") != page_size:
+    if (not entry or entry.get("page_size") != page_size
+            or entry.get("kv_heads") != kv_heads):
         if entry is not None and count:
             cache.misses += 1
         return None
@@ -306,10 +316,12 @@ def autotune_paged_decode(slots: int, logical_len: int, head_dim: int, *,
     key = tcache.cache_key("paged_decode", slots, logical_len, head_dim,
                            dtype_name, backend_name())
     hit = lookup_paged_decode(cache, key, page_size=page_size,
-                              max_pages=max_pages)
+                              kv_heads=kv_heads, max_pages=max_pages)
     if hit is not None:
         return hit
     q_heads = q_heads or kv_heads
+    geom = dict(kv_heads=kv_heads, head_dim=head_dim,
+                itemsize=jnp.dtype(dtype_name).itemsize)
     from repro import tuning
     if (not _on_tpu()
             and slots * q_heads * logical_len * head_dim
@@ -318,14 +330,14 @@ def autotune_paged_decode(slots: int, logical_len: int, head_dim: int, *,
         if log is not None:
             log(f"[autotune] {key}: skipped — interpret-mode cap; using the "
                 f"static pages_per_block (warm this cell on TPU)")
-        return default_pages_per_block(page_size, max_pages)
+        return default_pages_per_block(page_size, max_pages, **geom)
 
     interpret = not _on_tpu()
     q, k, v, pos, table, q_pos, ksc, vsc = steady_state_pool(
         slots, logical_len, head_dim, page_size=page_size,
         kv_heads=kv_heads, q_heads=q_heads, dtype_name=dtype_name)
 
-    candidates = paged_decode_candidates(page_size, max_pages)
+    candidates = paged_decode_candidates(page_size, max_pages, **geom)
     best_ppb, best_us = candidates[0], float("inf")
     for ppb in candidates:
         f = jax.jit(lambda q, k, v, pos, table, q_pos, ksc, vsc, ppb=ppb:
@@ -350,7 +362,7 @@ def autotune_paged_decode(slots: int, logical_len: int, head_dim: int, *,
     cache.put(key, cfg, measured_us=best_us,
               extra={"candidates_timed": len(candidates),
                      "kind": "paged_decode_ppb", "page_size": page_size,
-                     "window": window})
+                     "kv_heads": kv_heads, "window": window})
     cache.save()
     if log is not None:
         log(f"[autotune] {key}: pages_per_block={best_ppb} {best_us:.0f}us "

@@ -338,7 +338,8 @@ def test_resolve_pages_per_block_modes(tmp_path):
                                                resolve_pages_per_block)
     geom = dict(slots=2, logical_len=16, head_dim=8, page_size=4,
                 max_pages=4, dtype_name="float32")
-    static = default_pages_per_block(4, 4)
+    pool = dict(kv_heads=1, head_dim=8, itemsize=4)
+    static = default_pages_per_block(4, 4, **pool)
     assert resolve_pages_per_block(**geom) == static   # mode=model default
 
     cache = tuning.set_tile_cache(tcache.TileCache(path=None))
@@ -346,17 +347,68 @@ def test_resolve_pages_per_block_modes(tmp_path):
                            search.backend_name())
     cfg = elastic._make_config(2, 16, 8, elastic.SUBLANE, 128, 3,
                                "output_stationary", 4)
-    cache.put(key, cfg, extra={"page_size": 4})        # ppb=3: not the default
+    cache.put(key, cfg, extra={"page_size": 4,         # ppb=3: not the default
+                               "kv_heads": 1})
     tuning.set_tile_mode("cached")
     assert resolve_pages_per_block(**geom) == 3
     assert resolve_pages_per_block(**{**geom, "logical_len": 32,
                                      "max_pages": 8}) == \
-        default_pages_per_block(4, 8)                  # miss -> static
+        default_pages_per_block(4, 8, **pool)          # miss -> static
     # same m/k/n from a different page layout: the key under-determines the
     # cell, so the entry's recorded page_size gates the replay
     assert resolve_pages_per_block(**{**geom, "page_size": 2,
                                      "max_pages": 8}) == \
-        default_pages_per_block(2, 8)
+        default_pages_per_block(2, 8, **pool)
+    # ... and so does its kv_heads: a grid step fetches every head of a page
+    assert resolve_pages_per_block(**{**geom, "kv_heads": 2}) == \
+        default_pages_per_block(4, 4, **{**pool, "kv_heads": 2})
+
+
+def test_paged_decode_ppb_fits_the_vmem_budget():
+    """The untuned ppb keeps the 512-token stripe while a step's double-
+    buffered K + V blocks (every KV head of each page) fit the budget, and
+    lowers it for geometries with many KV heads; the autotuner's lattice
+    never offers a ppb over the budget."""
+    from repro.kernels.paged_attention import (KV_BLOCK_VMEM_BYTES,
+                                               default_pages_per_block,
+                                               page_block_bytes)
+    yi = dict(kv_heads=4, head_dim=128, itemsize=2)      # yi-6b, bf16 pool
+    assert default_pages_per_block(16, 128, **yi) == 32
+    assert 32 * page_block_bytes(16, **yi) == 2 << 20
+    wide = dict(kv_heads=32, head_dim=128, itemsize=2)
+    ppb = default_pages_per_block(16, 128, **wide)
+    assert 1 <= ppb < 32
+    assert ppb * page_block_bytes(16, **wide) <= KV_BLOCK_VMEM_BYTES
+    assert (ppb + 1) * page_block_bytes(16, **wide) > KV_BLOCK_VMEM_BYTES
+    geoms = (yi, wide, dict(kv_heads=8, head_dim=128, itemsize=1),
+             dict(kv_heads=32, head_dim=64, itemsize=2))
+    for g in geoms:
+        for ps, mp in ((8, 8), (16, 128), (1, 4096)):
+            cands = search.paged_decode_candidates(ps, mp, **g)
+            assert default_pages_per_block(ps, mp, **g) in cands
+            assert all(c * page_block_bytes(ps, **g) <= KV_BLOCK_VMEM_BYTES
+                       for c in cands)
+
+
+def test_paged_decode_entry_without_kv_heads_is_a_miss():
+    """A ``paged_decode`` entry tuned for the grid step per KV head (no
+    ``kv_heads`` recorded) never replays onto the folded kernel: it is a
+    counted miss, as is an entry for another head count."""
+    cache = tcache.TileCache(path=None)
+    key = tcache.cache_key("paged_decode", 8, 2048, 128, "bfloat16",
+                           search.backend_name())
+    cfg = elastic._make_config(8, 2048, 128, elastic.SUBLANE, 2048, 64,
+                               "output_stationary", 4)
+    look = dict(page_size=16, kv_heads=4, max_pages=128)
+    cache.put(key, cfg, extra={"page_size": 16})
+    assert search.lookup_paged_decode(cache, key, **look) is None
+    assert (cache.hits, cache.misses) == (0, 1)
+    cache.put(key, cfg, extra={"page_size": 16, "kv_heads": 4})
+    assert search.lookup_paged_decode(cache, key, **look) == 64
+    assert (cache.hits, cache.misses) == (1, 1)
+    assert search.lookup_paged_decode(cache, key,
+                                      **{**look, "kv_heads": 8}) is None
+    assert (cache.hits, cache.misses) == (1, 2)
 
 
 def test_serving_cells_dedup_and_coverage():

@@ -88,17 +88,25 @@ def test_kraken_matmul_compiles(one_chip, m, k, n):
 @pytest.mark.parametrize("ppb", ["default", "largest"])
 @pytest.mark.parametrize("quantized", [False, True])
 @pytest.mark.parametrize("page_size,max_len", [(8, 64), (PAGE_SIZE, MAX_LEN)])
-def test_paged_decode_compiles(one_chip, page_size, max_len, quantized, ppb):
-    """yi-6b GQA geometry (4 KV heads, group 8, head_dim 128) on 8-token
+@pytest.mark.parametrize("arch", [YI, MIXTRAL], ids=lambda a: a.name)
+def test_paged_decode_compiles(one_chip, arch, page_size, max_len, quantized,
+                               ppb):
+    """yi-6b's GQA geometry (4 KV heads, group 8, head_dim 128) and
+    mixtral-8x22b's (8 KV heads, group 6, its sliding window) on 8-token
     pages over 64 positions and on 16-token pages over 2048, at the default
-    pages-per-block and at the largest one the autotuner may pick."""
-    kvh, g, d = YI.num_kv_heads, YI.num_heads // YI.num_kv_heads, YI.head_dim
+    pages-per-block and at the largest one the autotuner may pick: a grid
+    step holds every KV head of its pages, so wider KV geometries are where
+    VMEM would refuse."""
+    kvh, d = arch.num_kv_heads, arch.head_dim
+    g = arch.num_heads // kvh
     mp = max_len // page_size
     n_pages = SLOTS * mp + 1
-    pages_per_block = (default_pages_per_block(page_size, mp)
-                       if ppb == "default"
-                       else max(paged_decode_candidates(page_size, mp)))
     pool = jnp.int8 if quantized else jnp.bfloat16
+    geom = dict(kv_heads=kvh, head_dim=d, itemsize=jnp.dtype(pool).itemsize)
+    pages_per_block = (default_pages_per_block(page_size, mp, **geom)
+                       if ppb == "default"
+                       else max(paged_decode_candidates(page_size, mp,
+                                                        **geom)))
     shapes = [((SLOTS, kvh * g, d), jnp.bfloat16),
               ((n_pages, kvh, page_size, d), pool),
               ((n_pages, kvh, page_size, d), pool),
@@ -111,7 +119,7 @@ def test_paged_decode_compiles(one_chip, page_size, max_len, quantized, ppb):
     def attend(q, k, v, pos, table, q_pos, k_scale=None, v_scale=None):
         return paged_decode_attention(
             q, k, v, pos_pages=pos, page_table=table, q_pos=q_pos,
-            k_scale=k_scale, v_scale=v_scale,
+            k_scale=k_scale, v_scale=v_scale, window=arch.sliding_window,
             pages_per_block=pages_per_block)
     assert "tpu_custom_call" in _compiled_text(attend, one_chip, *shapes)
 
