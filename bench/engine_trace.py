@@ -8,11 +8,8 @@ watchdog, admission with one ``engine.admit`` per request seated),
 ``engine.inputs``, ``engine.dispatch.<program>`` (with an
 ``engine.compile.<program>`` inside when the call compiles),
 ``engine.sample``, ``engine.advance`` (one ``engine.finish`` per retired
-request) and ``engine.push_tables``.  The Pallas kernels of the main path
-carry their names (``kraken_gemm``, ``paged_decode_attention``).
-
-:func:`extract` reads a trace as :func:`bench.trace.extract` does and
-adds the engine's spans and the name of every Pallas op; then
+request) and ``engine.push_tables``.  :func:`bench.trace.extract` keeps
+them beside the harness's spans.  From its record
 
 * :func:`host_idle` splits the window's program-free time (no XLA module
   runs on any device) over the innermost host span covering each
@@ -27,9 +24,11 @@ adds the engine's spans and the name of every Pallas op; then
 Run on a trace that ``bench/run.py --trace 1 --keep-trace DIR`` kept:
 
     python3 bench/engine_trace.py DIR [--record OUT.json.gz --ticks 3]
+                                     [--op-texts OUT.json]
 
 prints one JSON object; ``--record`` also writes the window's first
-decode ticks as a small record for the tests.
+decode ticks as a small record for the tests, ``--op-texts`` the HLO text
+of one device op of each kind.
 """
 
 from __future__ import annotations
@@ -49,45 +48,11 @@ if __name__ == "__main__":
 from bench import trace  # noqa: E402
 from bench.readings import DECODE  # noqa: E402
 
-ENGINE_PREFIX = "engine."
 STEP = "engine.step"
 DISPATCH_DECODE = "engine.dispatch.decode"
 PREPARE = ("engine.schedule", "engine.inputs", DISPATCH_DECODE)
 SAMPLE = ("engine.sample",)
 NO_SPAN = "host:other"
-# the name each main-path Pallas kernel is given, and the kind that
-# bench.trace.classify reads from its operands
-KERNELS = {"kraken_gemm": "pallas:gemm",
-           "paged_decode_attention": "pallas:paged_attention"}
-
-
-def extract(path: str) -> dict:
-    """:func:`bench.trace.extract` of ``path``, with the engine's spans
-    added to ``spans`` and, per device, ``kernels``: every Pallas op as
-    ``[name, kind, start_ns, duration_ns]``, its name the base name of
-    its HLO instruction (``pl.pallas_call(name=...)``; the enclosing
-    function's name where the kernel has none)."""
-    from jax.profiler import ProfileData
-    ex = trace.extract(path)
-    for plane in ProfileData.from_file(path).planes:
-        if plane.name in ex["devices"]:
-            kernels = ex["devices"][plane.name].setdefault("kernels", [])
-            for line in plane.lines:
-                if line.name != trace.OP_LINES[0]:
-                    continue
-                for e in line.events:
-                    kind = trace.classify(e.name)
-                    if kind.startswith("pallas:"):
-                        m = trace._BASE.match(e.name)
-                        kernels.append([m.group(1) if m else "", kind,
-                                        e.start_ns, e.duration_ns])
-        elif plane.name.startswith("/host:CPU"):
-            for line in plane.lines:
-                ex["spans"] += [[e.name, e.start_ns, e.duration_ns]
-                                for e in line.events
-                                if e.name.startswith(ENGINE_PREFIX)]
-    ex["spans"].sort(key=lambda s: s[1])
-    return ex
 
 
 def program_free(ex: dict, t0: float, t1: float) -> list:
@@ -146,8 +111,8 @@ def decode_ticks(ex: dict, t0: float, t1: float) -> list:
     """``(tick, children)`` of every ``engine.step`` inside ``[t0, t1]``
     that holds an ``engine.dispatch.decode``; its children are the engine
     spans that start inside it."""
-    spans = sorted((s for s in ex["spans"]
-                    if s[0].startswith(ENGINE_PREFIX)), key=lambda s: s[1])
+    spans = sorted((s for s in ex["spans"] if s[0].startswith("engine.")),
+                   key=lambda s: s[1])
     starts = [s[1] for s in spans]
     out = []
     for tick in spans:
@@ -198,16 +163,6 @@ def exposed(ex: dict, t0: float | None = None,
             "sample_exposed_ms.decode": mean_ms("sample")}
 
 
-def kernel_names(ex: dict) -> dict:
-    """``{name: {kind: ops}}`` over every Pallas op of the record."""
-    out: dict = {}
-    for dev in ex["devices"].values():
-        for name, kind, _, _ in dev.get("kernels", []):
-            kinds = out.setdefault(name, {})
-            kinds[kind] = kinds.get(kind, 0) + 1
-    return out
-
-
 def record(ex: dict, ticks: int) -> dict:
     """The first ``ticks`` decode ticks of the window, every event that
     starts inside them, the harness's spans that overlap them, and a
@@ -224,8 +179,7 @@ def record(ex: dict, ticks: int) -> dict:
     for name, dev in ex["devices"].items():
         out["devices"][name] = {
             "modules": [m for m in dev["modules"] if inside(m)],
-            "ops": [o for o in dev["ops"] if inside(o)],
-            "kernels": [k for k in dev.get("kernels", []) if inside(k)]}
+            "ops": [o for o in dev["ops"] if inside(o)]}
     out["spans"] += [s for s in ex["spans"] if s[0] != trace.WINDOW_SPAN
                      and s[1] < b and s[1] + s[2] > a]
     out["spans"].sort(key=lambda s: s[1])
@@ -236,8 +190,8 @@ def summary(ex: dict) -> dict:
     """What the engine's spans say of one trace, beside the numbers
     :func:`bench.trace.reduce` gives: the exposed times (means, and
     medians over decode ticks), the split of program-free time, the
-    period between decode calls back to back, compiles in the window,
-    the idle gaps named by span and the Pallas ops by name."""
+    period between decode calls back to back, compiles in the window
+    and the idle gaps named by span."""
     red = trace.reduce(ex)
     t0, t1 = red["t0_ns"], red["t1_ns"]
     free = program_free(ex, t0, t1)
@@ -260,8 +214,22 @@ def summary(ex: dict) -> dict:
         "host_idle_ms": {k: v * 1e3
                          for k, v in host_idle(ex, t0, t1).items()},
         "idle_gaps": red["idle_gaps"],
-        "kernels": kernel_names(ex),
     }
+
+
+def op_texts(path: str) -> dict:
+    """``{kind: HLO text}``: the first synchronous device op of each kind
+    that :func:`bench.trace.classify` reads in the trace file ``path``."""
+    from jax.profiler import ProfileData
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not trace.device_plane(plane.name):
+            continue
+        for line in plane.lines:
+            if line.name == trace.OP_LINES[0]:
+                for e in line.events:
+                    out.setdefault(trace.classify(e.name), e.name)
+    return dict(sorted(out.items()))
 
 
 def main(argv=None) -> int:
@@ -269,15 +237,18 @@ def main(argv=None) -> int:
     p.add_argument("trace", help="a kept trace directory or .xplane.pb")
     p.add_argument("--record", help="write the first decode ticks here")
     p.add_argument("--ticks", type=int, default=3)
+    p.add_argument("--op-texts", help="write one op text of each kind here")
     args = p.parse_args(argv)
     path = args.trace
     if Path(path).is_dir():
         path = sorted(glob.glob(str(Path(path) / "**" / "*.xplane.pb"),
                                 recursive=True))[-1]
-    ex = extract(path)
+    ex = trace.extract(path)
     if args.record:
         with gzip.open(args.record, "wt") as f:
             json.dump(record(ex, args.ticks), f)
+    if args.op_texts:
+        Path(args.op_texts).write_text(json.dumps(op_texts(path), indent=1))
     print(json.dumps(summary(ex)))
     return 0
 

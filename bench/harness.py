@@ -13,14 +13,16 @@ data or a small file of its own, found by the name in ``BENCHMARK.json``:
 
 The program under test is the serving engine (``PagedEngine``): the run
 drives ``submit()`` and ``step()`` and reads the requests it returns, its
-program call counters and its active slots.  Weights, traffic, the trace
-reduction, the work counts, the peaks and the reference are the
-benchmark's own.
+program call counters, its active slots, a copy of its ``stats()`` at
+each end of the window, and its host spans in a traced run.  Weights,
+traffic, the trace reduction, the work counts, the peaks and the
+reference are the benchmark's own.
 """
 
 from __future__ import annotations
 
 import contextlib
+import copy
 import dataclasses
 import gc
 import glob
@@ -371,7 +373,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     if require_chip:
         use_compile_cache()
     compiles = CompileCounter()
-    from bench import traffic, trace as tr
+    from bench import engine_trace, traffic, trace as tr
 
     c = config or load_json(ROOT / cfg_entry["file"])
     mix = mix or traffic.load_mix(cell["traffic"])
@@ -395,6 +397,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     t0 = time.monotonic()
     win.start(t0)
     win.run_until(t0 + mix["warmup_s"])
+    stats_ws = copy.deepcopy(eng.stats())
     ws = time.monotonic()
     setup_s = ws - t_process
     programs = (eng._prefill.retraces, eng._decode.retraces,
@@ -424,6 +427,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     else:
         win.run_until(ws + seconds)
         we = time.monotonic()
+    stats_we = copy.deepcopy(eng.stats())
     window_compiles = compiles.between(ws, we)
     new_programs = [a - b for a, b in zip(
         (eng._prefill.retraces, eng._decode.retraces, eng._reset.retraces),
@@ -442,6 +446,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         "calls": [r for r in win.calls[calls_before:]
                   if r["program"] is not None],
         "occupancy_slots": eng.slots, "lag": win.lag,
+        "stats": [stats_ws, stats_we],
         "family": fam, "peaks": None, "trace": None,
     }
     attempted = len(win.done) + len(win.failed) + len(win.live)
@@ -458,6 +463,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                                 recursive=True))[-1]
         ex = tr.extract(path)
         red = tr.reduce(ex)
+        red["engine"] = engine_trace.exposed(ex, red["t0_ns"], red["t1_ns"])
         red["calls"] = [r for r in win.calls
                         if r["program"] is not None and tt0 <= r["t"] <= tt1]
         record["trace"] = red

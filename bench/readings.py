@@ -1,5 +1,7 @@
 """Helpers the metric readers share: stamps in the window, percentiles,
-and the device time of each engine program in a trace reduction."""
+the device time of each engine program in a trace reduction, the
+engine's exposed host time in it, and how far an engine counter moved
+over the window."""
 
 from __future__ import annotations
 
@@ -34,6 +36,21 @@ def module(run, name):
 def traced_calls(run, program):
     return [r for r in run["trace"]["calls"] if r["program"] == program]
 
+
+def engine_exposed(run, name):
+    """One of ``bench/engine_trace.py``'s ``exposed`` numbers over the
+    traced window; None where the trace holds no engine spans."""
+    return ((run.get("trace") or {}).get("engine") or {}).get(name)
+
+
+def counter_delta(run, key):
+    """How far the engine's ``stats()[key]`` moved between the window's
+    two ends; None where the run kept no stats or the engine has no such
+    counter."""
+    stats = run.get("stats")
+    if not stats or key not in stats[0] or key not in stats[1]:
+        return None
+    return stats[1][key] - stats[0][key]
 
 
 def token_gaps_ms(run):

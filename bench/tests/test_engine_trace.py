@@ -1,8 +1,9 @@
 """The engine's host spans beside the device trace (``bench/engine_trace.py``):
 program-free time split over the innermost span, the decode tick's
-exposed host time, its preparation and its sampling; on hand-made events,
-on a TPU v5e trace recorded with the harness's spans only, and on one
-recorded with the engine's spans and the kernels' names."""
+exposed host time, its preparation and its sampling, and the readers that
+take them and the engine's counters; on hand-made events, on a TPU v5e
+trace recorded with the harness's spans only, and on one recorded with the
+engine's spans and the kernels' names."""
 
 import gzip
 import json
@@ -110,7 +111,6 @@ def test_a_trace_without_engine_spans_reads_none():
     assert all(got[k] is None for k in got if k.endswith(".decode"))
     assert all(k.startswith("bench.") or k == et.NO_SPAN
                for k in et.host_idle(ex))
-    assert et.kernel_names(ex) == {}
 
 
 def reader_run(ex):
@@ -153,10 +153,16 @@ def test_engine_spans_leave_the_readers_bit_identical():
     ex = load(NEW)
     bare = {"devices": {k: {"modules": d["modules"], "ops": d["ops"]}
                         for k, d in ex["devices"].items()},
-            "spans": [s for s in ex["spans"]
-                      if s[0].startswith(tr.SPAN_PREFIX)]}
+            "spans": [s for s in ex["spans"] if s[0].startswith("bench.")]}
     assert readings(ex) == readings(bare)
-    assert all(v is not None for v in readings(ex).values())
+    # as the readers gave them before the reduction kept engine spans
+    assert readings(ex) == {
+        "decode_step_ms": 42.229993666666665,
+        "mfu.decode": 1.1305647241619703,
+        "gemm_roofline.decode": 63.84066903104505,
+        "paged_attn_roofline": 1.4218883234065742,
+        "idle_share.decode": 7.026283920682619}
+    assert harness.load_reader("slot_occupancy")(reader_run(ex)) == 100.0
     gaps = tr.reduce(ex)["idle_gaps"]
     assert [g[1] for g in gaps] == [g[1] for g in
                                     tr.reduce(bare)["idle_gaps"]]
@@ -180,9 +186,55 @@ def test_recorded_v5e_trace_with_engine_spans():
 
 
 def test_kernel_names_agree_with_the_operand_rule():
-    """Every Pallas op of the recorded trace carries its kernel's name,
-    and ``classify``'s operand rule gives the kind that name stands for."""
-    names = et.kernel_names(load(NEW))
-    assert set(names) == set(et.KERNELS)
-    for name, kinds in names.items():
-        assert set(kinds) == {et.KERNELS[name]}, (name, kinds)
+    """Every Pallas op of the recorded trace carries its kernel's name
+    beside the kind the operand rule recorded for it; the name, through
+    ``bench.trace``'s one table, gives that kind."""
+    kernels = [k for d in load(NEW)["devices"].values()
+               for k in d["kernels"]]
+    ops = {tuple(o) for d in load(NEW)["devices"].values() for o in d["ops"]}
+    assert {name for name, *_ in kernels} == set(tr.KERNELS)
+    for name, kind, start, dur in kernels:
+        assert tr.kernel_kind(name) == kind, (name, kind)
+        assert (kind, start, dur) in ops
+
+
+EXPOSED = {"decode_ticks": 2, "host_exposed_ms.decode": 3.4,
+           "prepare_exposed_ms.decode": 1.0, "sample_exposed_ms.decode": 2.3}
+SPAN_READERS = ("host_exposed_ms.decode", "prepare_exposed_ms.decode",
+                "sample_exposed_ms.decode")
+
+
+def test_span_readers_read_the_engine_reduction():
+    for name in SPAN_READERS:
+        read = harness.load_reader(name)
+        assert read({"trace": {"engine": EXPOSED}}) == EXPOSED[name]
+        assert read({"trace": None}) is None
+        assert read({"trace": {"modules": {}}}) is None
+        # a window with no decode tick
+        assert read({"trace": {"engine": et.exposed(load(OLD))}}) is None
+
+
+def test_span_readers_on_the_recorded_trace():
+    """The harness reduces a traced window so: ``engine`` holds
+    :func:`exposed` over the same window as the rest of the reduction."""
+    ex = load(NEW)
+    run = reader_run(ex)
+    run["trace"]["engine"] = et.exposed(ex, run["trace"]["t0_ns"],
+                                        run["trace"]["t1_ns"])
+    got = {m: harness.load_reader(m)(run) for m in SPAN_READERS}
+    assert got == {m: et.exposed(ex)[m] for m in SPAN_READERS}
+    assert 3.0 < got["host_exposed_ms.decode"] < 3.6
+
+
+def test_slot_occupancy_engine_reads_the_counters_over_the_window():
+    read = harness.load_reader("slot_occupancy.engine")
+    start = {"steps": 40, "live_rows": 300, "ticks": 41}
+    # 100 program calls on 8 slots: 790 decode rows and 5 prefill rows
+    end = {"steps": 140, "live_rows": 1095, "ticks": 150}
+    assert read({"stats": [start, end], "occupancy_slots": 8}) \
+        == pytest.approx(100.0 * 795 / 800)
+    assert read({"stats": [start, start], "occupancy_slots": 8}) is None
+    assert read({"stats": None, "occupancy_slots": 8}) is None
+    assert read({"occupancy_slots": 8}) is None
+    assert read({"stats": [{"steps": 1}, {"steps": 2}],
+                 "occupancy_slots": 8}) is None

@@ -8,7 +8,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import harness, trace as tr
+from bench import engine_trace as et, harness, trace as tr
 from bench.models import llama
 
 DATA = Path(__file__).resolve().parent / "data"
@@ -35,11 +35,16 @@ def run():
     calls = [{"program": "decode", "t": 0.0, "prefill": [],
               "decode": list(range(300, 308)), "logits": 8, "live": 8}] * 2
     red["calls"] = calls
+    # the engine's spans come from the trace recorded with them
+    with gzip.open(DATA / "v5e_yi6b_decode_spans.json.gz", "rt") as f:
+        red["engine"] = et.exposed(json.load(f))
     # eight requests due at 1 s, admitted 0.1 s apart, a token every 47 ms
     reqs = [_Live(1.0, [1.5 + 0.047 * k for k in range(200)], 1.0 + 0.1 * i)
             for i in range(8)]
     return {"window": (1.0, 9.0), "setup_s": 42.0, "requests": reqs,
             "calls": calls, "occupancy_slots": 8, "trace": red,
+            "stats": [{"steps": 10, "live_rows": 80},
+                      {"steps": 30, "live_rows": 240}],
             "family": llama, "config": c,
             "peaks": peaks["devices"]["TPU v5 lite"]}
 
@@ -64,6 +69,10 @@ def test_decode_readings(run):
     assert read("output_tok_s")(run) == pytest.approx(8 * 160 / 8.0)
     assert read("itl_p95_ms")(run) == pytest.approx(47.0, abs=0.5)
     assert read("slot_occupancy")(run) == 100.0
+    assert read("slot_occupancy.engine")(run) == 100.0
+    assert 3.0 < read("host_exposed_ms.decode")(run) < 3.6
+    assert 0 < read("prepare_exposed_ms.decode")(run) < 1.5
+    assert 1.5 < read("sample_exposed_ms.decode")(run) < 3.0
     # 14.2 ms of least projection time over 22.25 ms of GEMM kernels and
     # the weight slices that feed them
     assert read("gemm_roofline.decode")(run) == pytest.approx(63.9, abs=1.0)

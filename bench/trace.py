@@ -8,14 +8,15 @@ trace without the profiler:
   module calls of each device plane ``[name, start_ns, duration_ns]``, its
   ops, synchronous and asynchronous, ``[kind, start_ns, duration_ns]``
   with the kind that :func:`classify` reads from the op's HLO text, and
-  the harness's host spans (``bench.*``).
+  the host spans of the harness (``bench.*``) and of the engine
+  (``engine.*``).
 * :func:`reduce` takes that record and the traced window (the harness's
   ``bench.traced`` span) and gives: device busy time (the union of op
   intervals, asynchronous copies included, averaged over devices), device
   time per module, time per kind of op (each op's own time: loops and
   asynchronous copies, whose bodies and waits are listed apart, left
-  out), and the longest idle gaps, each named by the host span it fell
-  in.
+  out), and the longest idle gaps, each named by the innermost host span
+  it fell in.
 """
 
 from __future__ import annotations
@@ -23,7 +24,7 @@ from __future__ import annotations
 import re
 from collections import defaultdict
 
-SPAN_PREFIX = "bench."
+SPAN_PREFIXES = ("bench.", "engine.")
 WINDOW_SPAN = "bench.traced"
 MODULES_LINE = "XLA Modules"
 OP_LINES = ("XLA Ops", "Async XLA Ops")
@@ -31,17 +32,26 @@ OP_LINES = ("XLA Ops", "Async XLA Ops")
 # that wait for them, and a loop's or call's body ops are listed too
 NESTING = ("async:", "xla:while", "xla:conditional", "xla:call")
 
+# the kind of a Pallas kernel whose name (``pl.pallas_call(name=...)``)
+# the readers know by another; every other kernel is ``pallas:<name>``
+KERNELS = {"kraken_gemm": "pallas:gemm",
+           "paged_decode_attention": "pallas:paged_attention"}
+
 _BASE = re.compile(r"%([A-Za-z_\-]+?)[.\d]* = ")
-_CUSTOM = re.compile(r'custom-call\((.*?)\), custom_call_target="([^"]+)"')
-_OPERAND = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\]")
+_TARGET = re.compile(r'custom-call\(.*?\), custom_call_target="([^"]+)"')
 _KIND = re.compile(r"kind=(k\w+)")
+
+
+def kernel_kind(name: str) -> str:
+    """The kind of the Pallas kernel named ``name``."""
+    return KERNELS.get(name, f"pallas:{name}")
 
 
 def classify(text: str, asynchronous: bool = False) -> str:
     """What a device op is, from its HLO text in the trace.  A Pallas
-    kernel (``tpu_custom_call``) is known by its operands: the paged
-    attention kernel takes its scalar-prefetch operands (``s32`` page
-    table and positions) first, a GEMM ``[M, K] @ [K, N]`` two matrices.
+    kernel (``tpu_custom_call``) is known by its instruction's base name,
+    the name ``pl.pallas_call(name=...)`` gave it (the enclosing
+    function's where it has none), through :func:`kernel_kind`.
     An XLA op that reads the program's parameters (``%params...``, the
     per-layer weight slices that feed the GEMMs) is ``xla:param_copy``,
     one that copies the KV pools (``%pools...``) ``xla:pool_copy``; any
@@ -51,18 +61,11 @@ def classify(text: str, asynchronous: bool = False) -> str:
     base = m.group(1) if m else text.split(" ")[0]
     if asynchronous:
         return f"async:{base}"
-    cc = _CUSTOM.search(text)
-    if cc:
-        if cc.group(2) != "tpu_custom_call":
-            return f"xla:custom-call:{cc.group(2)}"
-        ops = [(t, [int(x) for x in d.split(",") if x])
-               for t, d in _OPERAND.findall(cc.group(1))]
-        if ops and ops[0][0].startswith(("s", "u")):
-            return "pallas:paged_attention"
-        if (len(ops) >= 2 and len(ops[0][1]) == 2 and len(ops[1][1]) == 2
-                and ops[0][1][1] == ops[1][1][0]):
-            return "pallas:gemm"
-        return "pallas:other"
+    target = _TARGET.search(text)
+    if target:
+        if target.group(1) != "tpu_custom_call":
+            return f"xla:custom-call:{target.group(1)}"
+        return kernel_kind(base)
     args = text.split(" = ", 1)[-1]
     if "%params" in args:
         return "xla:param_copy"
@@ -80,11 +83,22 @@ def classify(text: str, asynchronous: bool = False) -> str:
 def extract(path: str) -> dict:
     """The events of one trace file that the reduction needs."""
     from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
+    return from_planes(ProfileData.from_file(path).planes)
+
+
+def device_plane(name: str) -> bool:
+    """Whether a trace plane is an accelerator's."""
+    return (name.startswith("/device:") and "CPU" not in name
+            and "CUSTOM" not in name)
+
+
+def from_planes(planes) -> dict:
+    """:func:`extract` of the planes of a ``ProfileData``: device planes
+    give modules and ops, host planes the spans named ``bench.*`` or
+    ``engine.*``; every other host event is dropped."""
     out = {"devices": {}, "spans": []}
-    for plane in pd.planes:
-        if plane.name.startswith("/device:") and "CPU" not in plane.name \
-                and "CUSTOM" not in plane.name:
+    for plane in planes:
+        if device_plane(plane.name):
             dev = {"modules": [], "ops": []}
             kinds: dict = {}
             for line in plane.lines:
@@ -105,7 +119,7 @@ def extract(path: str) -> dict:
             for line in plane.lines:
                 out["spans"] += [[e.name, e.start_ns, e.duration_ns]
                                  for e in line.events
-                                 if e.name.startswith(SPAN_PREFIX)]
+                                 if e.name.startswith(SPAN_PREFIXES)]
     out["spans"].sort(key=lambda s: s[1])
     return out
 
